@@ -93,6 +93,8 @@ def main(argv=None):
         print("QUICK SMOKE (pytest -m fast + compile/quant/fusion/serve/"
               "robust/fleet/decode benches --quick)")
         print("=" * 72)
+        # nothing above imports JAX, so an accelerator stays free for
+        # the child (a chip belongs to one process)
         rc = subprocess.call(
             [_sys.executable, "-m", "pytest", "-q", "-m", "fast"])
         entries = []

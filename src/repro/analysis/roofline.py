@@ -17,8 +17,8 @@ models per-device bytes-on-wire per op:
     all-to-all        out_bytes * (n-1)/n
     collective-permute out_bytes
 
-with n = replica-group size parsed per op.  Hardware constants: TPU v5e
-197 bf16 TFLOP/s, 819 GB/s HBM, ~50 GB/s/link ICI.
+with n = replica-group size parsed per op.  Peak rates come from
+:data:`PEAKS`, keyed by the chip's ``device_kind``.
 """
 from __future__ import annotations
 
@@ -27,9 +27,33 @@ import re
 from dataclasses import asdict, dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peak rates, per second."""
+    bf16_flops: float
+    hbm_bw: float                     # bytes
+    ici_link_bw: float                # bytes, one link
+
+
+#: Keyed by ``jax.Device.device_kind``.  TPU v5e ("TPU v5 lite"): Google
+#: Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at
+#: 819 GB/s, 1,600 Gbit/s ICI per chip over 4 links.
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(bf16_flops=197e12, hbm_bw=819e9,
+                             ici_link_bw=1600e9 / 8 / 4),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip; a kind missing from :data:`PEAKS` is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to PEAKS with their "
+                       f"source") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -139,14 +163,16 @@ class Roofline:
 
 
 def build_roofline(arch: str, shape: str, mesh_name: str, chips: int,
+                   device_kind: str,
                    flops_per_chip: float, bytes_per_chip: float,
                    wire_bytes_per_chip: float, model_flops: float,
                    collectives: Optional[Dict[str, float]] = None,
                    memory_per_chip: float = 0.0, note: str = ""
                    ) -> Roofline:
-    t_c = flops_per_chip / PEAK_FLOPS
-    t_m = bytes_per_chip / HBM_BW
-    t_x = wire_bytes_per_chip / ICI_BW
+    pk = peaks(device_kind)
+    t_c = flops_per_chip / pk.bf16_flops
+    t_m = bytes_per_chip / pk.hbm_bw
+    t_x = wire_bytes_per_chip / pk.ici_link_bw
     terms = {"compute": t_c, "memory": t_m, "collective": t_x}
     bottleneck = max(terms, key=terms.get)
     dom = max(t_c, t_m, t_x)

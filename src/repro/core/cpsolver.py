@@ -44,6 +44,7 @@ falling back to in-process serial solving when the platform cannot fork.
 from __future__ import annotations
 
 import os
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
@@ -694,6 +695,16 @@ def _run_task(task: SolveTask) -> Solution:
               stall_limit_nodes=task.stall_limit_nodes)
 
 
+def _holds_accelerator() -> bool:
+    """True once this process has brought up a non-CPU JAX backend."""
+    if "jax" not in sys.modules:
+        return False
+    import jax
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized() and \
+        jax.default_backend() != "cpu"
+
+
 def solve_many(tasks: Sequence[SolveTask], parallel: bool = True,
                max_workers: Optional[int] = None) -> List[Solution]:
     """Solve independent CP models, concurrently when possible.
@@ -708,12 +719,15 @@ def solve_many(tasks: Sequence[SolveTask], parallel: bool = True,
     Forking a multi-threaded process can deadlock the child (e.g. after
     jax spins up its runtime threads), and a deadlock is a hang, not an
     exception — so the pool is only used from single-threaded processes
-    and every wait carries a deadline.
+    and every wait carries a deadline.  A process that holds an
+    accelerator never forks: its runtime threads are invisible to
+    ``threading``, and the child would inherit the device's handles.
     """
     import threading
 
     tasks = list(tasks)
-    if len(tasks) <= 1 or not parallel or threading.active_count() > 1:
+    if len(tasks) <= 1 or not parallel or threading.active_count() > 1 \
+            or _holds_accelerator():
         return [_run_task(t) for t in tasks]
     ex = None
     try:

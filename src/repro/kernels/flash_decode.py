@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -72,7 +71,7 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                  kv_len: Optional[jnp.ndarray] = None,
                  sm_scale: Optional[float] = None,
                  block_k: int = 256, return_lse: bool = False,
-                 interpret: bool = True):
+                 interpret: bool = False):
     """q (B,H,D); k (B,Hkv,S,D); v (B,Hkv,S,Dv); kv_len (B,)."""
     B, H, D = q.shape
     _, Hkv, S, _ = k.shape
@@ -117,7 +116,7 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, Dv), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(kv_len.astype(jnp.int32), q.reshape(B, H, 1, D), k, v)

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 from .ref import apply_activation
 
 
@@ -85,7 +83,7 @@ def neutron_matmul(x: jnp.ndarray, w: jnp.ndarray,
                    out_scale: Optional[float] = None,
                    block_m: int = 128, block_n: int = 128,
                    block_k: int = 512,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool = False) -> jnp.ndarray:
     """y[M,N] = requant(act(scale * (x[M,K] @ w[K,N]) + bias))."""
     M, K = x.shape
     K2, N = w.shape
@@ -141,7 +139,7 @@ def neutron_matmul(x: jnp.ndarray, w: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

@@ -2,41 +2,69 @@
 
 Every op has two implementations with identical semantics:
 
-  * ``pallas``  — the TPU-target kernel (``interpret=True`` on CPU, so it
-    runs the kernel body in Python; correct but slow);
-  * ``ref``     — the pure-jnp oracle (fast under jit on CPU, and what the
-    models use when not running on TPU).
+  * ``pallas``  — the TPU kernel, compiled by Mosaic;
+  * ``ref``     — the pure-jnp oracle.
 
-``impl="auto"`` picks pallas on TPU and ref elsewhere, so the same model
-code is TPU-native in production and CPU-testable here.  Tests pin
-``impl="pallas"`` (interpret) vs ``impl="ref"`` and assert allclose.
+``impl="auto"`` picks the Pallas kernel on a TPU and the jnp path on any
+other backend.  It also picks the jnp path in two places where a kernel
+cannot run: under a mesh whose axes GSPMD partitions (a ``pallas_call``
+cannot be partitioned automatically; inside ``shard_map`` every device
+holds whole operands and the kernel runs), and inside :func:`use_jnp`.
+
+Off the TPU a Pallas kernel runs only in interpret mode, and only when
+the caller asks for it with ``interpret=True``: ``impl="pallas"`` on
+another backend without it is an error, never a silent interpretation.
 """
 from __future__ import annotations
 
-import math
-from functools import partial
+import contextlib
+import contextvars
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from . import ref as _ref
 from .flash_attention import flash_attention as _flash_attention_pallas
 from .flash_decode import flash_decode as _flash_decode_pallas
 from .neutron_matmul import neutron_matmul as _neutron_matmul_pallas
-from .ssd_scan import ssd_chunk as _ssd_chunk_pallas
+from .ssd_scan import ssd_scan as _ssd_scan_pallas
+
+_USE_JNP = contextvars.ContextVar("use_jnp", default=False)
 
 
-def _on_tpu() -> bool:
+@contextlib.contextmanager
+def use_jnp():
+    """Trace the ``auto`` ops inside with their jnp implementations.
+
+    The Pallas ``flash_attention`` and ``ssd_scan`` have no backward
+    pass, so code that differentiates through them (the train step)
+    selects the jnp path here, at its call site."""
+    token = _USE_JNP.set(True)
     try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+        yield
+    finally:
+        _USE_JNP.reset(token)
 
 
-def _resolve(impl: str) -> str:
+def _gspmd_partitioned() -> bool:
+    """True when the active mesh has an Auto axis of size > 1."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return any(mesh.shape[name] > 1 and kind == AxisType.Auto
+               for name, kind in zip(mesh.axis_names, mesh.axis_types))
+
+
+def _resolve(impl: str, interpret: bool) -> str:
+    on_tpu = jax.default_backend() == "tpu"
     if impl == "auto":
-        return "pallas" if _on_tpu() else "ref"
+        pallas = on_tpu and not _USE_JNP.get() and not _gspmd_partitioned()
+        return "pallas" if pallas else "ref"
+    if impl == "pallas" and not (on_tpu or interpret):
+        raise RuntimeError(
+            f"impl='pallas' needs a TPU (backend is "
+            f"{jax.default_backend()!r}); pass interpret=True to run the "
+            f"kernel body in the Pallas interpreter")
     return impl
 
 
@@ -47,13 +75,12 @@ def _resolve(impl: str) -> str:
 
 def neutron_matmul(x, w, bias=None, scale=None, act: str = "none",
                    out_dtype=None, out_scale: Optional[float] = None,
-                   impl: str = "auto", **block_kw):
-    impl = _resolve(impl)
-    if impl == "ref":
+                   impl: str = "auto", interpret: bool = False,
+                   **block_kw):
+    if _resolve(impl, interpret) == "ref":
         return _ref.neutron_matmul_ref(x, w, bias=bias, scale=scale,
                                        act=act, out_dtype=out_dtype,
                                        out_scale=out_scale)
-    interpret = not _on_tpu()
     return _neutron_matmul_pallas(x, w, bias=bias, scale=scale, act=act,
                                   out_dtype=out_dtype, out_scale=out_scale,
                                   interpret=interpret, **block_kw)
@@ -67,16 +94,15 @@ def neutron_matmul(x, w, bias=None, scale=None, act: str = "none",
 def flash_attention(q, k, v, causal: bool = True,
                     window: Optional[int] = None,
                     sm_scale: Optional[float] = None,
-                    impl: str = "auto", fused_vjp: bool = True,
-                    **block_kw):
+                    impl: str = "auto", interpret: bool = False,
+                    fused_vjp: bool = True, **block_kw):
     """q (B,H,S,D); k/v (B,Hkv,Sk,D).
 
     ``fused_vjp`` uses the FlashAttention-2-style custom backward
     (O(S·D) residuals).  ``fused_vjp=False`` differentiates through the
     forward scan — the naive baseline that stacks O(S²) residuals,
     kept selectable for the §Perf before/after measurement."""
-    impl = _resolve(impl)
-    if impl == "ref":
+    if _resolve(impl, interpret) == "ref":
         H, Hkv = q.shape[1], k.shape[1]
         if H != Hkv:
             g = H // Hkv
@@ -88,7 +114,6 @@ def flash_attention(q, k, v, causal: bool = True,
                 block_kw.get("block_k", 512))
         return _ref.flash_attention_ref(q, k, v, causal=causal,
                                         window=window, sm_scale=sm_scale)
-    interpret = not _on_tpu()
     return _flash_attention_pallas(q, k, v, causal=causal, window=window,
                                    sm_scale=sm_scale, interpret=interpret,
                                    **block_kw)
@@ -100,10 +125,10 @@ def flash_attention(q, k, v, causal: bool = True,
 
 
 def flash_decode(q, k, v, kv_len=None, sm_scale: Optional[float] = None,
-                 return_lse: bool = False, impl: str = "auto", **block_kw):
+                 return_lse: bool = False, impl: str = "auto",
+                 interpret: bool = False, **block_kw):
     """q (B,H,D); k/v (B,Hkv,S,D)."""
-    impl = _resolve(impl)
-    if impl == "ref":
+    if _resolve(impl, interpret) == "ref":
         H, Hkv = q.shape[1], k.shape[1]
         if H != Hkv:
             g = H // Hkv
@@ -112,7 +137,6 @@ def flash_decode(q, k, v, kv_len=None, sm_scale: Optional[float] = None,
         return _ref.flash_decode_ref(q, k, v, kv_len=kv_len,
                                      sm_scale=sm_scale,
                                      return_lse=return_lse)
-    interpret = not _on_tpu()
     return _flash_decode_pallas(q, k, v, kv_len=kv_len, sm_scale=sm_scale,
                                 return_lse=return_lse, interpret=interpret,
                                 **block_kw)
@@ -127,46 +151,17 @@ combine_decode_shards = _ref.combine_decode_shards
 
 
 def ssd_scan(x, dt, A, Bm, Cm, chunk: int = 64, init_state=None,
-             impl: str = "auto") -> Tuple[jnp.ndarray, jnp.ndarray]:
+             impl: str = "auto", interpret: bool = False
+             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full chunked SSD: intra-chunk kernel + cross-chunk jnp recurrence.
 
     x (B,S,H,P); dt (B,S,H); A (H,); Bm/Cm (B,S,N).
     Returns (y (B,S,H,P), final_state (B,H,P,N))."""
-    impl = _resolve(impl)
-    if impl == "ref":
+    if _resolve(impl, interpret) == "ref":
         return _ref.ssd_scan_ref(x, dt, A, Bm, Cm, chunk=chunk,
                                  init_state=init_state)
-    Bsz, S, H, P = x.shape
-    N = Bm.shape[-1]
-    nc = math.ceil(S / chunk)
-    pad = nc * chunk - S
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0)))
-        Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0)))
-    interpret = not _on_tpu()
-    y_in, contrib, total, seg = _ssd_chunk_pallas(
-        x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret)
-
-    def scan_state(s_prev, inp):
-        contrib_c, total_c = inp
-        return s_prev * total_c[..., None, None] + contrib_c, s_prev
-
-    s0 = (init_state.astype(jnp.float32) if init_state is not None
-          else jnp.zeros((Bsz, H, P, N), dtype=jnp.float32))
-    s_final, s_prevs = jax.lax.scan(
-        scan_state, s0,
-        (contrib.transpose(1, 0, 2, 3, 4), total.transpose(1, 0, 2)))
-    s_prevs = s_prevs.transpose(1, 0, 2, 3, 4)            # (B,nc,H,P,N)
-    L = chunk
-    segc = seg.reshape(Bsz, nc, L, H)
-    Cc = Cm.reshape(Bsz, nc, L, N).astype(jnp.float32)
-    y_out = jnp.einsum("bcln,bclh,bchpn->bclhp", Cc, jnp.exp(segc),
-                       s_prevs)
-    y = (y_in.reshape(Bsz, nc, L, H, P) +
-         y_out).reshape(Bsz, nc * L, H, P)[:, :S]
-    return y.astype(x.dtype), s_final.astype(x.dtype)
+    return _ssd_scan_pallas(x, dt, A, Bm, Cm, chunk=chunk,
+                            init_state=init_state, interpret=interpret)
 
 
 ssd_step = _ref.ssd_step_ref          # O(1) decode step (pure jnp)

@@ -36,7 +36,11 @@ from repro.analysis import roofline as rl
 from repro.analysis.hlo import analyze_hlo
 from repro.models.registry import (ARCH_IDS, SHAPES, build_step, cells,
                                    get_arch)
-from .mesh import make_production_mesh, named_shardings, use_mesh
+from .mesh import make_production_mesh, named_shardings
+
+#: The chip of the production pods the dry-run plans for (TPU v5e).  The
+#: compile runs on host devices, so the kind is named, not queried.
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def run_cell(arch: str, shape: str, multi_pod: bool = False,
@@ -55,7 +59,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool = False,
 
     t0 = time.monotonic()
     bundle = build_step(cfg, shape, with_pod=multi_pod)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             bundle.fn,
             in_shardings=named_shardings(mesh, bundle.in_specs),
@@ -93,6 +97,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool = False,
 
     roof = rl.build_roofline(
         arch=arch, shape=shape, mesh_name=mesh_name, chips=chips,
+        device_kind=TARGET_DEVICE_KIND,
         flops_per_chip=hc.flops, bytes_per_chip=hc.bytes,
         wire_bytes_per_chip=hc.wire_bytes,
         model_flops=rl.model_flops_for(cfg, ss),

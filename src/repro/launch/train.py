@@ -3,7 +3,9 @@ with fault-tolerant restart and elastic re-mesh.
 
 End-to-end example (CPU, reduced config):
     PYTHONPATH=src python -m repro.launch.train --arch minitron-4b \
-        --smoke --steps 30 --ckpt-dir /tmp/ckpt
+        --smoke --steps 30 --ckpt-dir ckpt
+
+``--full`` trains the arch at its published widths instead.
 
 On a real pod the same driver runs under `jax.distributed.initialize()`
 with the production mesh; here the mesh defaults to every local device.
@@ -30,7 +32,8 @@ from repro.models.registry import get_arch, state_specs
 from repro.models.train import (TrainOptions, init_train_state,
                                 make_train_step)
 from repro.runtime.fault import FaultMonitor
-from .mesh import make_mesh, named_shardings, use_mesh
+from .compile_cache import enable_compile_cache
+from .mesh import make_mesh, named_shardings
 
 
 def train_loop(arch: str, steps: int = 30, smoke: bool = True,
@@ -59,7 +62,7 @@ def train_loop(arch: str, steps: int = 30, smoke: bool = True,
     ckpt = CheckpointManager(ckpt_dir) if ckpt_dir else None
     start_step = 0
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_train_state(cfg, jax.random.PRNGKey(seed), opts=opts)
         if ckpt is not None and ckpt.latest_step() is not None:
             state, start_step, meta = ckpt.restore(state)
@@ -112,6 +115,7 @@ def main():
     ap.add_argument("--compress", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     losses = train_loop(args.arch, steps=args.steps, smoke=args.smoke,
                         ckpt_dir=args.ckpt_dir,
                         ckpt_every=args.ckpt_every,

@@ -123,7 +123,7 @@ def attention(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
         o = ops.flash_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), causal=True, window=w,
-            impl=cfg.kernel_impl, fused_vjp=cfg.fused_attn_vjp,
+            fused_vjp=cfg.fused_attn_vjp,
             block_k=cfg.attn_block_k)
         o = o.transpose(0, 2, 1, 3).reshape(B, S, Hp * hd)
         o = _mask_padded(o, H, Hp, hd)
@@ -144,8 +144,7 @@ def attention(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
             cv, v.transpose(0, 2, 1, 3).astype(cv.dtype),
             (0, 0, cache_pos, 0))
         kv_len = jnp.full((B,), cache_pos + 1, dtype=jnp.int32)
-        o = ops.flash_decode(qd, ck, cv, kv_len=kv_len,
-                             impl=cfg.kernel_impl)
+        o = ops.flash_decode(qd, ck, cv, kv_len=kv_len)
     o = o.reshape(B, H * hd)
     if Hp != H:
         o = jnp.pad(o, ((0, 0), (0, (Hp - H) * hd)))
@@ -186,7 +185,7 @@ def _decode_seq_sharded(q3, k_new, v_new, ck, cv, pos, cfg: ArchConfig):
         o, lse = ops.flash_decode(
             q3, ck2, cv2,
             kv_len=jnp.full((q3.shape[0],), kv_len, jnp.int32),
-            return_lse=True, impl=cfg.kernel_impl)
+            return_lse=True)
         outs = jax.lax.all_gather(o, "model")
         lses = jax.lax.all_gather(lse, "model")
         return combine_decode_shards(outs, lses), ck2, cv2
@@ -241,7 +240,7 @@ def decode_windowed(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
     kv_len = jnp.full((B,), jnp.minimum(cache_pos + 1, window),
                       dtype=jnp.int32)
     o = ops.flash_decode(q[:, 0][:, :H].reshape(B, H, hd), ck, cv,
-                         kv_len=kv_len, impl=cfg.kernel_impl)
+                         kv_len=kv_len)
     o = o.reshape(B, H * hd)
     if Hp != H:
         o = jnp.pad(o, ((0, 0), (0, (Hp - H) * hd)))
@@ -317,7 +316,6 @@ def mla_attention(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
                                 k_all.transpose(0, 2, 1, 3),
                                 v_all.transpose(0, 2, 1, 3),
                                 causal=True, sm_scale=sm,
-                                impl=cfg.kernel_impl,
                                 fused_vjp=cfg.fused_attn_vjp,
                                 block_k=cfg.attn_block_k)
         o = o.transpose(0, 2, 1, 3).reshape(B, S, H * dv)
@@ -326,5 +324,5 @@ def mla_attention(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
                          k_all.transpose(0, 2, 1, 3),
                          v_all.transpose(0, 2, 1, 3),
                          kv_len=jnp.full((B,), kv_len, dtype=jnp.int32),
-                         sm_scale=sm, impl=cfg.kernel_impl)
+                         sm_scale=sm)
     return (o.reshape(B, H * dv) @ p["wo"])[:, None, :], kv_cache

@@ -77,7 +77,6 @@ class ArchConfig:
 
     dtype: str = "bfloat16"
     remat: bool = True                # activation checkpoint per layer
-    use_pallas: bool = False          # kernels impl ("auto" when True)
     fsdp: bool = False                # shard params over the data axis too
     fused_attn_vjp: bool = True       # FlashAttention-2 custom backward
     attn_block_k: int = 512           # KV streaming block size
@@ -124,10 +123,6 @@ class ArchConfig:
         global layers shard KV by sequence."""
         return self.family in ("ssm", "hybrid") or \
             self.local_global_ratio > 0
-
-    @property
-    def kernel_impl(self) -> str:
-        return "auto" if self.use_pallas else "ref"
 
     def n_params(self) -> int:
         """Analytic parameter count (embedding + layers + head)."""

@@ -424,8 +424,7 @@ def _bidir_attention(p: Dict, x, cfg: ArchConfig, positions):
     o = ops.flash_attention(q.transpose(0, 2, 1, 3),
                             k.transpose(0, 2, 1, 3),
                             v.transpose(0, 2, 1, 3),
-                            causal=False, impl=cfg.kernel_impl,
-                            fused_vjp=cfg.fused_attn_vjp,
+                            causal=False, fused_vjp=cfg.fused_attn_vjp,
                             block_k=cfg.attn_block_k)
     o = o.transpose(0, 2, 1, 3).reshape(B, S, Hp * hd)
     return _mask_padded(o, H, Hp, hd) @ p["wo"]
@@ -448,7 +447,7 @@ def _cross_attention(p: Dict, x, enc, cfg: ArchConfig,
         kx = _expand_kv(k.transpose(0, 2, 1, 3), H, Hkv, Hp)
         vx = _expand_kv(v.transpose(0, 2, 1, 3), H, Hkv, Hp)
         k, v = kx.transpose(0, 2, 1, 3), vx.transpose(0, 2, 1, 3)
-    o = ops.flash_attention(q, k, v, causal=False, impl=cfg.kernel_impl,
+    o = ops.flash_attention(q, k, v, causal=False,
                             fused_vjp=cfg.fused_attn_vjp,
                             block_k=cfg.attn_block_k)
     o = o.transpose(0, 2, 1, 3).reshape(B, S, Hp * hd)

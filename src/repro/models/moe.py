@@ -171,20 +171,8 @@ def moe_a2a(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
     from .sharding import active_mesh_axes, mesh_axis_size
 
     E = cfg.n_experts
-    if mesh is not None:
-        n_model = mesh.shape[model_axis]
-        from jax.experimental.shard_map import shard_map as _sm
-
-        def shard_map(f, in_specs, out_specs):
-            return _sm(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
-    else:
-        n_model = mesh_axis_size(model_axis)
-
-        def shard_map(f, in_specs, out_specs):
-            return jax.shard_map(f, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-
+    n_model = (mesh.shape[model_axis] if mesh is not None
+               else mesh_axis_size(model_axis))
     assert E % n_model == 0, (E, n_model)
     e_loc = E // n_model
     B, S, d = x.shape
@@ -223,12 +211,12 @@ def moe_a2a(p: Dict, x: jnp.ndarray, cfg: ArchConfig,
         y = combine(ye)
         return y.reshape(Bl, Sl, d).astype(x.dtype)
 
-    fn = shard_map(
-        local,
+    fn = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(P(data_spec, model_axis, None), P(None, None),
                   P(model_axis, None, None), P(model_axis, None, None),
                   P(model_axis, None, None)),
-        out_specs=P(data_spec, model_axis, None))
+        out_specs=P(data_spec, model_axis, None), check_vma=False)
     # (output replication over `model` is by math — round-trip
     # all_to_all — hence replication checking is disabled)
     y = fn(x, p["router"], p["experts"]["w_in"], p["experts"]["w_gate"],

@@ -200,10 +200,13 @@ def tree_partition_specs(params: Any, model_axis: str = "model",
 class MeshSpec:
     n_data: int
     n_model: int
+    device_kind: str                    # key of analysis.roofline.PEAKS
     n_pod: int = 1
-    flops_per_chip: float = 197e12      # bf16 TPU v5e
-    hbm_gbps: float = 819e9
-    ici_gbps: float = 50e9              # per link
+
+    @property
+    def peaks(self):
+        from repro.analysis.roofline import peaks
+        return peaks(self.device_kind)
 
 
 @dataclass
@@ -239,21 +242,21 @@ class FormatPlanner:
         if fmt == "depth":
             # TP: weights split n_model ways; activations replicated;
             # row-parallel partner needs one all-reduce of the output.
-            t_compute = flops / m.n_model / m.flops_per_chip
+            t_compute = flops / m.n_model / m.peaks.bf16_flops
             coll = 2.0 * ls.tokens * ls.d_out * ls.bytes_per_elt \
                 * (m.n_model - 1) / m.n_model
-            t_coll = coll / m.ici_gbps
+            t_coll = coll / m.peaks.ici_link_bw
         else:
             # line/SP: tokens split; params broadcast (all-gather weights)
-            t_compute = flops / m.n_model / m.flops_per_chip
+            t_compute = flops / m.n_model / m.peaks.bf16_flops
             coll = ls.d_in * ls.d_out * ls.bytes_per_elt \
                 * (m.n_model - 1) / m.n_model
-            t_coll = coll / m.ici_gbps
+            t_coll = coll / m.peaks.ici_link_bw
         w_bytes = ls.d_in * ls.d_out * ls.bytes_per_elt / m.n_model
         a_bytes = ls.tokens * (ls.d_in + ls.d_out) * ls.bytes_per_elt
         if fmt == "line":
             a_bytes /= m.n_model
-        t_mem = (w_bytes + a_bytes) / m.hbm_gbps
+        t_mem = (w_bytes + a_bytes) / m.peaks.hbm_bw
         return max(t_compute, t_mem) + t_coll
 
     def choose(self, ls: LayerShape) -> FormatChoice:

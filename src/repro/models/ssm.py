@@ -88,8 +88,7 @@ def ssm_block(p: Dict, h: jnp.ndarray, cfg: ArchConfig,
         xs = xBC[..., :di].reshape(B, S, H, P)
         Bm = xBC[..., di:di + N]
         Cm = xBC[..., di + N:]
-        y, _ = ops.ssd_scan(xs, dt, A, Bm, Cm, chunk=cfg.ssm_chunk,
-                            impl=cfg.kernel_impl)
+        y, _ = ops.ssd_scan(xs, dt, A, Bm, Cm, chunk=cfg.ssm_chunk)
         y = (y + xs * p["D"][None, None, :, None]).astype(h.dtype)
         y = y.reshape(B, S, di)
         y = rms_norm(p["gnorm"], y * jax.nn.silu(z), cfg.norm_eps)

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import optim
+from repro.kernels import ops
 from repro.runtime.overlap import accumulate_grads
 from .config import ArchConfig
 from . import lm
@@ -65,8 +66,10 @@ def make_train_step(cfg: ArchConfig,
 
     def train_step(state: TrainState, batch: Dict
                    ) -> Tuple[TrainState, Dict]:
-        loss, grads = accumulate_grads(lsf, state.params, batch,
-                                       opts.n_micro)
+        # differentiated: the Pallas attention/SSD kernels have no VJP
+        with ops.use_jnp():
+            loss, grads = accumulate_grads(lsf, state.params, batch,
+                                           opts.n_micro)
         err = state.error_fb
         if opts.compress_grads and err is not None:
             grads, err = optim.compress_grads(grads, err)

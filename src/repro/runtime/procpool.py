@@ -70,6 +70,7 @@ import multiprocessing as mp
 import os
 import signal
 import struct
+import sys
 import threading
 import time
 import zlib
@@ -259,9 +260,12 @@ def _worker_main(conn, wid: int, model_paths: Dict[str, str],
 
     for name, path in model_paths.items():
         _load(name, path)
+    # "jax": children replay numpy plans only; one process per chip
+    # holds the accelerator, so a child must never import JAX
     conn.send_bytes(pack_frame({
         "type": "ready", "wid": wid, "pid": os.getpid(),
-        "models": sorted(models), "errors": dict(load_errors)}))
+        "models": sorted(models), "errors": dict(load_errors),
+        "jax": "jax" in sys.modules}))
 
     seq = 0
     while True:

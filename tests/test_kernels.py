@@ -1,11 +1,32 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret
-mode (the kernel body executes on CPU)."""
+mode (the kernel body executes on CPU; each call asks for the
+interpreter explicitly)."""
+import jax
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
 
 RNG = np.random.default_rng(0)
+
+
+@pytest.mark.parametrize("op,args", [
+    ("neutron_matmul", ((8, 16), (16, 8))),
+    ("flash_attention", ((1, 2, 8, 16), (1, 2, 8, 16), (1, 2, 8, 16))),
+    ("flash_decode", ((1, 2, 16), (1, 2, 8, 16), (1, 2, 8, 16))),
+    ("ssd_scan", ((1, 8, 2, 4), (1, 8, 2), (2,), (1, 8, 4), (1, 8, 4))),
+])
+def test_pallas_off_tpu_needs_explicit_interpret(op, args):
+    """Off the TPU, ``auto`` takes the jnp path and ``pallas`` without
+    ``interpret=True`` is refused, never interpreted in silence."""
+    arrays = [np.ones(s, np.float32) for s in args]
+    fn = getattr(ops, op)
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        fn(*arrays, impl="pallas")
+    want = fn(*arrays, impl="ref")
+    for g, w in zip(*(jax.tree_util.tree_leaves(o)
+                      for o in (fn(*arrays), want))):
+        np.testing.assert_array_equal(g, w)
 
 
 # --------------------------------------------------------------------------
@@ -19,7 +40,7 @@ RNG = np.random.default_rng(0)
 def test_neutron_matmul_shapes(m, k, n, dtype):
     x = RNG.normal(size=(m, k)).astype(dtype)
     w = RNG.normal(size=(k, n)).astype(dtype)
-    got = ops.neutron_matmul(x, w, impl="pallas")
+    got = ops.neutron_matmul(x, w, impl="pallas", interpret=True)
     want = ops.neutron_matmul(x, w, impl="ref")
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -33,7 +54,8 @@ def test_neutron_matmul_activations(act):
     x = RNG.normal(size=(32, 64)).astype(np.float32)
     w = RNG.normal(size=(64, 48)).astype(np.float32)
     b = RNG.normal(size=(48,)).astype(np.float32)
-    got = ops.neutron_matmul(x, w, bias=b, act=act, impl="pallas")
+    got = ops.neutron_matmul(x, w, bias=b, act=act, impl="pallas",
+                             interpret=True)
     want = ops.neutron_matmul(x, w, bias=b, act=act, impl="ref")
     np.testing.assert_allclose(got, want, atol=2e-3, rtol=1e-3)
 
@@ -42,7 +64,7 @@ def test_neutron_matmul_int8_requant_bit_exact():
     x = RNG.integers(-128, 128, size=(64, 256)).astype(np.int8)
     w = RNG.integers(-128, 128, size=(256, 96)).astype(np.int8)
     got = ops.neutron_matmul(x, w, scale=np.float32(0.02), act="relu",
-                             out_scale=0.7, impl="pallas")
+                             out_scale=0.7, impl="pallas", interpret=True)
     want = ops.neutron_matmul(x, w, scale=np.float32(0.02), act="relu",
                               out_scale=0.7, impl="ref")
     assert got.dtype == np.int8
@@ -53,7 +75,7 @@ def test_neutron_matmul_per_channel_scale():
     x = RNG.integers(-64, 64, size=(16, 128)).astype(np.int8)
     w = RNG.integers(-64, 64, size=(128, 32)).astype(np.int8)
     sc = RNG.uniform(0.001, 0.1, size=(32,)).astype(np.float32)
-    got = ops.neutron_matmul(x, w, scale=sc, impl="pallas")
+    got = ops.neutron_matmul(x, w, scale=sc, impl="pallas", interpret=True)
     want = ops.neutron_matmul(x, w, scale=sc, impl="ref")
     np.testing.assert_allclose(got, want, atol=1e-3, rtol=1e-4)
 
@@ -73,7 +95,7 @@ def test_flash_attention_sweep(B, H, Hkv, S, D, causal):
     k = RNG.normal(size=(B, Hkv, S, D)).astype(np.float32)
     v = RNG.normal(size=(B, Hkv, S, D)).astype(np.float32)
     got = ops.flash_attention(q, k, v, causal=causal, impl="pallas",
-                              block_q=32, block_k=32)
+                              interpret=True, block_q=32, block_k=32)
     want = ops.flash_attention(q, k, v, causal=causal, impl="ref")
     np.testing.assert_allclose(got, want, atol=2e-3, rtol=1e-3)
 
@@ -85,7 +107,7 @@ def test_flash_attention_sliding_window(window):
     k = RNG.normal(size=(B, H, S, D)).astype(np.float32)
     v = RNG.normal(size=(B, H, S, D)).astype(np.float32)
     got = ops.flash_attention(q, k, v, window=window, impl="pallas",
-                              block_q=32, block_k=32)
+                              interpret=True, block_q=32, block_k=32)
     want = ref.attention_naive(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(got, want, atol=2e-3, rtol=1e-3)
 
@@ -96,8 +118,8 @@ def test_flash_attention_mla_head_dims():
     q = RNG.normal(size=(B, H, S, Dqk)).astype(np.float32)
     k = RNG.normal(size=(B, H, S, Dqk)).astype(np.float32)
     v = RNG.normal(size=(B, H, S, Dv)).astype(np.float32)
-    got = ops.flash_attention(q, k, v, impl="pallas", block_q=16,
-                              block_k=16)
+    got = ops.flash_attention(q, k, v, impl="pallas", interpret=True,
+                              block_q=16, block_k=16)
     want = ops.flash_attention(q, k, v, impl="ref")
     assert got.shape == (B, H, S, Dv)
     np.testing.assert_allclose(got, want, atol=2e-3, rtol=1e-3)
@@ -108,8 +130,8 @@ def test_flash_attention_bf16():
     q = RNG.normal(size=(B, H, S, D)).astype("bfloat16")
     k = RNG.normal(size=(B, H, S, D)).astype("bfloat16")
     v = RNG.normal(size=(B, H, S, D)).astype("bfloat16")
-    got = ops.flash_attention(q, k, v, impl="pallas", block_q=32,
-                              block_k=32)
+    got = ops.flash_attention(q, k, v, impl="pallas", interpret=True,
+                              block_q=32, block_k=32)
     want = ops.flash_attention(q, k, v, impl="ref")
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -152,7 +174,7 @@ def test_flash_decode_sweep(B, H, Hkv, S, D):
     v = RNG.normal(size=(B, Hkv, S, D)).astype(np.float32)
     kvl = RNG.integers(1, S + 1, size=(B,)).astype(np.int32)
     got, lg = ops.flash_decode(q, k, v, kv_len=kvl, return_lse=True,
-                               impl="pallas", block_k=64)
+                               impl="pallas", interpret=True, block_k=64)
     want, lw = ops.flash_decode(q, k, v, kv_len=kvl, return_lse=True,
                                 impl="ref")
     np.testing.assert_allclose(got, want, atol=2e-3, rtol=1e-3)
@@ -193,7 +215,8 @@ def test_ssd_scan_sweep(B, S, H, P, N, chunk):
     A = -RNG.uniform(0.5, 2.0, size=(H,)).astype(np.float32)
     Bm = RNG.normal(size=(B, S, N)).astype(np.float32)
     Cm = RNG.normal(size=(B, S, N)).astype(np.float32)
-    yg, sg = ops.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, impl="pallas")
+    yg, sg = ops.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, impl="pallas",
+                          interpret=True)
     yw, sw = ops.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, impl="ref")
     np.testing.assert_allclose(yg, yw, atol=2e-3, rtol=1e-3)
     np.testing.assert_allclose(sg, sw, atol=2e-3, rtol=1e-3)

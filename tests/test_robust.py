@@ -794,6 +794,31 @@ def test_chaos_frame_flip_targets_payload_frames():
     procpool.unpack_frame(res)                       # original intact
 
 
+def test_process_worker_never_imports_jax():
+    """Pool children replay numpy plans only.  An accelerator belongs to
+    one process, so a spawned child must come up without JAX."""
+    import multiprocessing as mp
+
+    from repro.runtime import procpool
+
+    ctx = mp.get_context("spawn")
+    parent, child = ctx.Pipe(duplex=True)
+    proc = ctx.Process(target=procpool._worker_main,
+                       args=(child, 0, {}, 0.05, 0), daemon=True)
+    proc.start()
+    child.close()
+    try:
+        assert parent.poll(60), "worker never reported ready"
+        header, _ = procpool.unpack_frame(parent.recv_bytes())
+        assert header["type"] == "ready"
+        assert header["jax"] is False
+        parent.send_bytes(procpool.pack_frame({"type": "close"}))
+    finally:
+        proc.join(10)
+        if proc.is_alive():
+            proc.kill()
+
+
 @pytest.mark.chaos
 def test_process_pool_frame_corruption_zero_ticket_loss():
     """A bit-flipped reply frame fails only its own batch — the batch

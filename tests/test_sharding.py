@@ -53,7 +53,7 @@ def test_tree_specs_match_structure():
 
 
 def test_format_planner_prefers_depth_for_wide_layers():
-    mesh = MeshSpec(n_data=16, n_model=16)
+    mesh = MeshSpec(n_data=16, n_model=16, device_kind="TPU v5 lite")
     pl = FormatPlanner(mesh)
     # huge d_out, few tokens -> depth (TP); tiny weights, many tokens
     # -> line (token split: the all-gathered weight bytes are trivial)
@@ -63,6 +63,16 @@ def test_format_planner_prefers_depth_for_wide_layers():
                                 d_out=64))
     assert thin.fmt == "line"
     assert wide.t_depth <= wide.t_line * 2     # depth competitive
+
+
+def test_peaks_unknown_device_kind_is_an_error():
+    from repro.analysis.roofline import peaks
+    assert peaks("TPU v5 lite").hbm_bw == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")
+    with pytest.raises(KeyError):
+        FormatPlanner(MeshSpec(n_data=1, n_model=1, device_kind="cpu")) \
+            .choose(LayerShape("x", tokens=8, d_in=8, d_out=8))
 
 
 def test_hlo_analyzer_counts_loop_trips():
@@ -89,8 +99,8 @@ def test_hlo_analyzer_collectives():
         return jax.lax.with_sharding_constraint(
             x.sum(axis=0, keepdims=True), P(None, None))
 
-    from repro.launch.mesh import named_shardings, use_mesh
-    with use_mesh(mesh):
+    from repro.launch.mesh import named_shardings
+    with jax.set_mesh(mesh):
         c = jax.jit(f, in_shardings=named_shardings(mesh, P("d", None)),
                     out_shardings=named_shardings(
                         mesh, P(None, None))).lower(
