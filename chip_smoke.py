@@ -243,12 +243,16 @@ def phase_serve(dev) -> None:
     require(((res.tokens >= 0) & (res.tokens < cfg.vocab)).all(),
             "generated token ids inside the vocabulary")
     peak = dev.memory_stats().get("peak_bytes_in_use", 0)
+    pre, dec = res.prefill, res.decode
     log(f"[serve] bring-up reading (not a benchmark): compile "
         f"{res.compile_s:.2f} s (set-up), prefill {prompt_len} tok x "
-        f"{batch}: {res.prefill_s * 1e3:.1f} ms, decode {gen} tok x "
-        f"{batch}: {res.decode_s * 1e3:.1f} ms = "
-        f"{gen * batch / res.decode_s:.1f} tok/s, peak HBM "
+        f"{batch}: {pre.seconds * 1e3:.1f} ms, decode {gen} tok x "
+        f"{batch}: {dec.seconds * 1e3:.1f} ms = "
+        f"{gen * batch / dec.seconds:.1f} tok/s, peak HBM "
         f"{peak / 2 ** 30:.2f} GiB")
+    log(f"[serve] host: prefill dispatch_s {pre.dispatch_s:.4f} sync_s "
+        f"{pre.sync_s:.4f}, decode dispatch_s {dec.dispatch_s:.4f} sync_s "
+        f"{dec.sync_s:.4f}")
 
 
 def phase_correctness() -> None:

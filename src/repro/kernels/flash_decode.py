@@ -119,6 +119,7 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_decode",
     )(kv_len.astype(jnp.int32), q.reshape(B, H, 1, D), k, v)
     o = out.reshape(B, H, Dv)
     if return_lse:

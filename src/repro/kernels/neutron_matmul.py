@@ -142,5 +142,6 @@ def neutron_matmul(x: jnp.ndarray, w: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="neutron_matmul",
     )(*args)
     return out[:M, :N]

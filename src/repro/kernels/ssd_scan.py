@@ -124,6 +124,7 @@ def ssd_chunk(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="ssd_scan",
     )(xr.reshape(Bsz * nc, H, L, P), dtr.reshape(Bsz * nc, H, L, 1),
       ar, br.reshape(Bsz * nc, H, L, N), cr.reshape(Bsz * nc, H, L, N))
 

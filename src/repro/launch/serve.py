@@ -13,13 +13,29 @@ MLA latent / SSD state / ring buffers).  Prefill here replays tokens
 through decode steps (identical math; the dry-run's prefill cell lowers
 the fused full-sequence path).  The step is compiled before the clock
 starts, and every timed phase ends in ``block_until_ready``.
+
+The lockstep loop is two functions, :func:`prefill` and :func:`decode`,
+which the benchmark drives too.  Each names its work in the profiler's
+own trace (``jax.profiler`` annotations, on the clock the device's
+events share; each is one cheap object while no trace is recorded):
+
+  serve.prompt   the prompt phase (args ``batch``, ``steps``)
+  serve.decode   the decode phase (args ``batch``, ``steps``)
+  serve.step     one dispatch of the step (``StepTraceAnnotation``;
+                 ``step_num`` counts this process's steps)
+  serve.sync     the host's ``block_until_ready`` closing a phase
+
+and returns two host-clock counters, always on (:class:`PhaseTimes`):
+a long ``dispatch_s`` is a host stall, a long ``sync_s`` after a normal
+``dispatch_s`` a pause of the chip or the runtime beneath the host.
 """
 from __future__ import annotations
 
 import argparse
+import itertools
 import time
 from functools import partial
-from typing import NamedTuple, Optional
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,11 +48,30 @@ from .compile_cache import enable_compile_cache
 from .mesh import make_mesh, named_shardings
 
 
+SPAN_PROMPT = "serve.prompt"
+SPAN_DECODE = "serve.decode"
+SPAN_STEP = "serve.step"
+SPAN_SYNC = "serve.sync"
+
+#: ``step_num`` of the next ``serve.step`` span in this process
+_STEP_NUM = itertools.count()
+
+
+class PhaseTimes(NamedTuple):
+    """Host-clock counters of one phase of the loop."""
+    dispatch_s: float             # phase start to the last dispatch back
+    sync_s: float                 # the wait in ``block_until_ready``
+
+    @property
+    def seconds(self) -> float:
+        return self.dispatch_s + self.sync_s
+
+
 class ServeResult(NamedTuple):
     tokens: np.ndarray            # (batch, gen) greedy generations
     compile_s: float              # set-up: compiling the decode step
-    prefill_s: float              # prompt replay, synced
-    decode_s: float               # generation, synced
+    prefill: PhaseTimes           # prompt replay, synced
+    decode: PhaseTimes            # generation, synced
 
 
 def place_params(cfg, mesh, seed: int):
@@ -75,6 +110,45 @@ def make_step(cfg, mesh, cache, aux=None):
                    out_shardings=(repl, repl, cache_sh))
 
 
+def _run(step, params, cache, tok, feed, pos, span: str
+         ) -> Tuple[List, object, PhaseTimes]:
+    """Dispatches the step at each ``pos[t]``, fed ``feed[i]`` or, where
+    ``feed`` is None, the token it just made; then syncs."""
+    out = []
+    n = len(pos)
+    with jax.profiler.TraceAnnotation(span, batch=tok.shape[0], steps=n):
+        t0 = time.monotonic()
+        for i in range(n):
+            with jax.profiler.StepTraceAnnotation(SPAN_STEP,
+                                                  step_num=next(_STEP_NUM)):
+                _, tok, cache = step(params, cache,
+                                     tok if feed is None else feed[i],
+                                     pos[i])
+            out.append(tok)
+        t1 = time.monotonic()
+        with jax.profiler.TraceAnnotation(SPAN_SYNC):
+            tok.block_until_ready()
+        t2 = time.monotonic()
+    return out, cache, PhaseTimes(t1 - t0, t2 - t1)
+
+
+def prefill(step, params, cache, prompt: Sequence, pos: Sequence):
+    """Replays a prompt (teacher-forced decode): ``prompt[t]``, a (batch,)
+    device array, at position ``pos[t]`` for t < len(prompt); syncs on
+    the first generated token.  Returns ``(tok, cache, times)``."""
+    out, cache, times = _run(step, params, cache, prompt[0], prompt,
+                             pos[:len(prompt)], SPAN_PROMPT)
+    return out[-1], cache, times
+
+
+def decode(step, params, cache, tok, pos: Sequence, start: int, n: int):
+    """``n`` greedy steps from ``tok`` at positions ``pos[start:start+n]``;
+    syncs on the last.  Returns ``(tokens, cache, times)``, ``tokens``
+    the n (batch,) device arrays the steps made."""
+    return _run(step, params, cache, tok, None, pos[start:start + n],
+                SPAN_DECODE)
+
+
 def serve(arch: str, *, smoke: bool, batch: int = 4, prompt_len: int = 32,
           gen: int = 16, seed: int = 0, max_len: Optional[int] = None
           ) -> ServeResult:
@@ -103,34 +177,30 @@ def serve(arch: str, *, smoke: bool, batch: int = 4, prompt_len: int = 32,
         step = make_step(cfg, mesh, cache, aux).lower(
             params, cache, prompts[:, 0], jnp.int32(0)).compile()
         t_compile = time.monotonic() - t0
+        repl = named_shardings(mesh, None)
+        pos = [jax.device_put(np.int32(t), repl) for t in range(max_len)]
+        prompt = [jax.device_put(prompts[:, t], repl)
+                  for t in range(prompt_len)]
         # warm-up on a throwaway cache: SSM and conv states are recurrent,
         # so a step replayed on the served cache would change its answers
         _, tok, _ = step(params, place_cache(cfg, mesh, batch, max_len),
-                         prompts[:, 0], jnp.int32(0))
+                         prompt[0], pos[0])
         tok.block_until_ready()
 
-        # prefill by replaying the prompt (teacher-forced decode)
-        t0 = time.monotonic()
-        for t in range(prompt_len):
-            _, tok, cache = step(params, cache, prompts[:, t],
-                                 jnp.int32(t))
-        tok.block_until_ready()
-        t_prefill = time.monotonic() - t0
+        tok, cache, t_prefill = prefill(step, params, cache, prompt, pos)
+        out, cache, t_decode = decode(step, params, cache, tok, pos,
+                                      prompt_len, gen)
 
-        out = []
-        t0 = time.monotonic()
-        for t in range(prompt_len, prompt_len + gen):
-            out.append(tok)
-            _, tok, cache = step(params, cache, tok, jnp.int32(t))
-        tok.block_until_ready()
-        t_decode = time.monotonic() - t0
-
-    gen_tokens = np.stack([np.asarray(t) for t in out], axis=1)
+    # the last step's token lies past ``gen``: the loop makes it, unread
+    gen_tokens = np.stack([np.asarray(t) for t in [tok, *out[:-1]]],
+                          axis=1)
     print(f"compile decode step: {t_compile:.2f} s (set-up)")
-    print(f"prefill {prompt_len} toks x {batch} streams: "
-          f"{t_prefill*1e3:.1f} ms")
-    print(f"decode  {gen} toks x {batch} streams: {t_decode*1e3:.1f} ms "
-          f"({gen*batch/max(t_decode,1e-9):.1f} tok/s)")
+    for what, n, t in (("prefill", prompt_len, t_prefill),
+                       ("decode ", gen, t_decode)):
+        print(f"{what} {n} toks x {batch} streams: {t.seconds*1e3:.1f} ms"
+              f" (dispatch {t.dispatch_s*1e3:.1f} ms, sync "
+              f"{t.sync_s*1e3:.1f} ms)")
+    print(f"decode: {gen*batch/max(t_decode.seconds,1e-9):.1f} tok/s")
     print("sample generations (first stream):", gen_tokens[0][:12])
     return ServeResult(gen_tokens, t_compile, t_prefill, t_decode)
 

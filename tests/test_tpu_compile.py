@@ -88,6 +88,80 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# kernel -> the case above that compiles it
+NAMED = {"flash_decode": "flash_decode_minitron",
+         "flash_attention": "flash_attention_minitron",
+         "ssd_scan": "ssd_scan_mamba2",
+         "neutron_matmul": "neutron_matmul_bf16"}
+
+
+def custom_calls(text):
+    """The compiled HLO's Mosaic kernels, each as the profiler's device
+    trace names an operation: its instruction text."""
+    return [line.strip().removeprefix("ROOT ")
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("kernel", sorted(NAMED))
+def test_kernel_carries_its_name(kernel, one_chip):
+    """Each ``pallas_call`` is named, so a refactor of its wrapper cannot
+    rename what a device trace shows of it."""
+    fn, operands = CASES[NAMED[kernel]]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in operands]
+    calls = custom_calls(jax.jit(fn).lower(*args).compile().as_text())
+    assert calls and all(f"/{kernel}/pallas_call" in c for c in calls)
+
+
+def test_step_keeps_the_names_the_benchmark_reads(one_chip):
+    """The serving step compiles to the module ``jit_step`` and holds the
+    ``flash_decode`` kernel under a name the benchmark's readers find
+    (``harness.STEP_MODULE``, ``flash_decode.roofline``'s ``is_kernel``):
+    minitron-4b at its widths, cut to two layers, on one described v5e.
+    The kernels are picked as on a TPU, which this process is not."""
+    import dataclasses
+    from unittest import mock
+
+    from benchmarks.tpu import harness, spec
+    from repro.kernels import ops
+    from repro.launch.mesh import make_mesh, named_shardings
+    from repro.launch.serve import make_step
+    from repro.models.registry import (abstract_cache, abstract_params,
+                                       cache_specs, get_arch, state_specs)
+
+    cfg = dataclasses.replace(get_arch("minitron-4b"), n_layers=2)
+    B, S = 4, 96
+    mesh = make_mesh(1, 1, devices=list(one_chip.device_set))
+
+    def shaped(tree, specs):
+        return jax.tree_util.tree_map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=sh),
+            tree, named_shardings(mesh, specs))
+
+    params = abstract_params(cfg)
+    params = shaped(params, state_specs(cfg, params))
+    cache = abstract_cache(cfg, B, S)
+    cache = shaped(cache, cache_specs(cfg, cache, "decode_32k",
+                                      n_model=1))
+    repl = named_shardings(mesh, None)
+    tok = jax.ShapeDtypeStruct((B,), I32, sharding=repl)
+    pos = jax.ShapeDtypeStruct((), I32, sharding=repl)
+    on_tpu = {"auto": "pallas"}
+    with mock.patch.object(ops, "_resolve",
+                           lambda impl, interpret: on_tpu.get(impl, impl)):
+        text = make_step(cfg, mesh, cache).lower(
+            params, cache, tok, pos).compile().as_text()
+    assert text.startswith(f"HloModule {harness.STEP_MODULE},")
+    roofline = spec.load_module(
+        ROOT / "benchmarks" / "tpu" / "metrics" / "flash_decode.roofline.py",
+        "bench_metric_flash_decode_roofline")
+    calls = custom_calls(text)
+    assert calls and all(roofline.is_kernel(c) for c in calls)
+    assert all("/flash_decode/pallas_call" in c for c in calls)
+
+
 @pytest.mark.parametrize("where", ["repo", "alone"])
 def test_chip_smoke_refuses_without_tpu(where, tmp_path):
     """Off the chip, or away from the repository, the smoke script exits
