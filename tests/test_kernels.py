@@ -2,10 +2,12 @@
 mode (the kernel body executes on CPU; each call asks for the
 interpreter explicitly)."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.flash_decode import VMEM_BUDGET, decode_tiles
 
 RNG = np.random.default_rng(0)
 
@@ -165,20 +167,62 @@ def test_flash_fused_vjp_grads():
 # --------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("B,H,Hkv,S,D", [
-    (1, 1, 1, 32, 8), (3, 4, 2, 200, 32), (2, 8, 8, 128, 64),
+@pytest.mark.parametrize("B,H,Hkv,S,D,Dv,block_k,empty", [
+    pytest.param(1, 1, 1, 32, 8, 8, 64, 0, id="1-1-1-32-8"),
+    pytest.param(3, 4, 2, 200, 32, 32, 64, 0, id="3-4-2-200-32"),
+    pytest.param(2, 8, 8, 128, 64, 64, 64, 0, id="2-8-8-128-64"),
+    pytest.param(8, 6, 2, 64, 32, 32, 64, 0, id="gqa3-tile8"),
+    pytest.param(9, 6, 2, 40, 16, 16, 64, 0, id="tiles-of-3"),
+    pytest.param(11, 3, 1, 24, 16, 16, 64, 0, id="tile-of-1"),
+    pytest.param(4, 48, 1, 16, 32, 32, 64, 2, id="mqa48-empty-rows"),
+    pytest.param(2, 4, 4, 64, 192, 128, 64, 0, id="mla-d192-dv128"),
+    pytest.param(2, 6, 2, 320, 16, 16, 256, 0, id="s320-no-pad"),
+    pytest.param(2, 4, 2, 260, 16, 16, 64, 0, id="s260-padded"),
 ])
-def test_flash_decode_sweep(B, H, Hkv, S, D):
+def test_flash_decode_sweep(B, H, Hkv, S, D, Dv, block_k, empty):
     q = RNG.normal(size=(B, H, D)).astype(np.float32)
     k = RNG.normal(size=(B, Hkv, S, D)).astype(np.float32)
-    v = RNG.normal(size=(B, Hkv, S, D)).astype(np.float32)
+    v = RNG.normal(size=(B, Hkv, S, Dv)).astype(np.float32)
     kvl = RNG.integers(1, S + 1, size=(B,)).astype(np.int32)
+    kvl[:empty] = 0
     got, lg = ops.flash_decode(q, k, v, kv_len=kvl, return_lse=True,
-                               impl="pallas", interpret=True, block_k=64)
+                               impl="pallas", interpret=True,
+                               block_k=block_k)
     want, lw = ops.flash_decode(q, k, v, kv_len=kvl, return_lse=True,
                                 impl="ref")
-    np.testing.assert_allclose(got, want, atol=2e-3, rtol=1e-3)
+    # a row with no key gives output 0 and the log-sum-exp NEG_INF, which
+    # combine_decode_shards weighs 0 (the reference averages V there)
+    np.testing.assert_allclose(got[empty:], want[empty:], atol=2e-3,
+                               rtol=1e-3)
+    np.testing.assert_array_equal(got[:empty], 0.0)
     np.testing.assert_allclose(lg, lw, atol=2e-3, rtol=1e-3)
+
+
+@pytest.mark.parametrize("shape,bt,bk,grid", [
+    pytest.param((64, 24, 8, 256, 128, 128), 8, 256, (8, 8, 1),
+                 id="minitron-4b.chat-b64"),
+    # granite-20b's per-chip shard of a 192-row cache over 4 chips
+    pytest.param((32, 48, 1, 48, 128, 128), 8, 48, (4, 1, 1),
+                 id="granite-20b.tp4.chat-b32"),
+    pytest.param((4, 24, 8, 320, 128, 128), 4, 160, (1, 8, 2),
+                 id="minitron-4b.chat-b4"),
+    pytest.param((16, 128, 128, 512, 192, 128), 8, 256, (2, 128, 2),
+                 id="mla-like"),
+    pytest.param((4, 8, 2, 1001, 128, 128), 4, 256, (1, 2, 4),
+                 id="no-divisor"),
+])
+def test_decode_tiles(shape, bt, bk, grid):
+    """The decode grid comes from the shapes: a cell per KV head and tile
+    of batch rows, KV blocks that divide S where one can, and a cell's
+    double-buffered blocks and f32 temporaries inside the VMEM budget."""
+    B, H, Hkv, S, D, Dv = shape
+    t = decode_tiles(B, H, Hkv, S, D, Dv, jnp.bfloat16)
+    assert (t.bt, t.bk, t.grid) == (bt, bk, grid)
+    assert B % t.bt == 0 and t.s_pad % t.bk == 0 and t.s_pad >= S
+    if any(S % b == 0 for b in range(8, 257, 8)) or S <= 256:
+        assert t.s_pad == S
+    assert t.bk <= 256 and (t.bk == S or t.bk % 8 == 0)
+    assert t.vmem_bytes <= VMEM_BUDGET
 
 
 def test_decode_shard_combine_exact():

@@ -41,6 +41,21 @@ CASES = {
         lambda q, k, v, n: flash_decode(q, k, v, kv_len=n, return_lse=True),
         [((4, 48, 128), BF), ((4, 1, 16, 128), BF), ((4, 1, 16, 128), BF),
          ((4,), I32)]),
+    # the benchmark cells' decode shapes: minitron-4b.chat-b64 (a 256-row
+    # cache), granite-20b.tp4.chat-b32's per-chip shard of 48 rows, and
+    # minitron-4b.chat-b4 (320 rows, two blocks of 160)
+    "flash_decode_chat_b64": (
+        lambda q, k, v, n: flash_decode(q, k, v, kv_len=n),
+        [((64, 24, 128), BF), ((64, 8, 256, 128), BF),
+         ((64, 8, 256, 128), BF), ((64,), I32)]),
+    "flash_decode_granite_chat_b32": (
+        lambda q, k, v, n: flash_decode(q, k, v, kv_len=n, return_lse=True),
+        [((32, 48, 128), BF), ((32, 1, 48, 128), BF), ((32, 1, 48, 128), BF),
+         ((32,), I32)]),
+    "flash_decode_chat_b4": (
+        lambda q, k, v, n: flash_decode(q, k, v, kv_len=n),
+        [((4, 24, 128), BF), ((4, 8, 320, 128), BF), ((4, 8, 320, 128), BF),
+         ((4,), I32)]),
     # minitron-4b prefill: 64 tokens, 32 (padded) heads, kv expanded
     "flash_attention_minitron": (
         lambda q, k, v: flash_attention(q, k, v, causal=True, block_k=512),
