@@ -35,9 +35,10 @@ class Work:
 
 
 def weight_bytes(c: Dict[str, Any], batch: int) -> float:
-    """Bytes of weights one decode step reads: every matrix once, the
-    embedding's looked-up rows."""
-    from .weights import leaves
+    """Bytes of weights one decode step reads: every matrix once in each
+    layer of its stack, the embedding's looked-up rows."""
+    from .weights import leaves, stack_counts, stack_of
+    layers = stack_counts(c)
     total = 0.0
     for lf in leaves(c):
         n = 1
@@ -45,7 +46,8 @@ def weight_bytes(c: Dict[str, Any], batch: int) -> float:
             n *= s
         if lf.path == "embed":
             n = batch * c["d_model"]
-        total += n * BYTES[lf.dtype] * (c["n_layers"] if lf.stacked else 1)
+        total += n * BYTES[lf.dtype] * (layers[stack_of(lf)] if lf.stacked
+                                        else 1)
     return total
 
 

@@ -78,17 +78,24 @@ class Reference:
 
     ``logits(tokens, first)`` runs tokens (K, T) and returns float32
     logits (K, T - first, V) for positions ``first..T-1``; it runs one
-    layer at a time, drawing that layer's weights on the device and
-    dropping them before the next."""
+    layer at a time in ``weights.layer_order``, drawing that layer's
+    weights on the device and dropping them before the next.  A family
+    that declares ``stacks`` gets the layer's stack as ``layer``'s last
+    argument, ``stack``."""
 
     def __init__(self, config: Dict[str, Any], seed: int,
                  quant: Optional[str] = None):
         self.c = config
         self.words = jnp.asarray(weights.seed_words(seed))
         self.quant = quant
-        self._layer_w = jax.jit(partial(weights.layer, config))
-        self._layer = jax.jit(partial(family(config["family"]).layer,
-                                      config, quant))
+        fam = family(config["family"])
+        self._layer_w, self._layer = {}, {}
+        for s in weights.stack_counts(config):
+            own = {"stack": s} if hasattr(fam, "stacks") else {}
+            self._layer_w[s] = jax.jit(partial(weights.layer, config,
+                                               stack=s))
+            self._layer[s] = jax.jit(partial(fam.layer, config, quant,
+                                             **own))
 
     def logits(self, tokens: np.ndarray, first: int) -> jax.Array:
         c = self.c
@@ -97,9 +104,9 @@ class Reference:
                      axis=0).astype(jnp.float32)
         head, norm = g["lm_head"], g["final_norm"]
         del g
-        for l in range(c["n_layers"]):
-            w = self._layer_w(self.words, jnp.int32(l))
-            h = self._layer(h, w)
+        for s, i in weights.layer_order(c):
+            w = self._layer_w[s](self.words, jnp.int32(i))
+            h = self._layer[s](h, w)
             del w
         return jax.jit(self._head)(h[:, first:], norm, head)
 

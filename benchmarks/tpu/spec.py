@@ -9,8 +9,10 @@ Each is one JSON file, found by its name:
     metrics/<metric>.py       ``read(ctx)`` for one per-layer metric
     drivers/<driver>.py       ``run_window(...)`` for one serving loop
     families/<family>.py      a model family's sizes, weight leaves,
-                              reference layer and step work, named by
-                              the configuration's ``family``
+                              reference layer, step work and, where
+                              its layers are not all of one kind, its
+                              layer stacks, named by the
+                              configuration's ``family``
 
 Adding a cell adds files and entries; nothing here is edited.
 """

@@ -10,14 +10,18 @@ for tensor parallelism).  The reference draws the same values with
 :func:`layer` and :func:`globals_`, one layer at a time, from the same
 seed: it takes nothing that the program made.
 
-A layer-stacked leaf of layer ``l`` is drawn from
-``fold_in(fold_in(root, crc32(path)), l)``, so one layer can be drawn
+A family's layers lie in one or more stacks: a stack is the first
+component of its leaves' paths, and ``families/<family>.py`` may name
+them in layer order with ``stacks(config)`` (:func:`stacks`).  A stacked
+leaf of its stack's layer ``i`` is drawn from
+``fold_in(fold_in(root, crc32(path)), i)``, so one layer can be drawn
 alone and gives the same bits as the stacked draw.
 """
 from __future__ import annotations
 
 import math
 import zlib
+from collections import Counter
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Tuple
 
@@ -37,7 +41,8 @@ class Leaf:
     shape: Tuple[int, ...]     # canonical shape of one layer's leaf
     rule: Callable             # (key, shape) -> float32 values, see draw
     dtype: str                 # the type it is served in
-    stacked: bool              # one per layer, under "layers/"
+    stacked: bool              # one per layer of the stack that is
+                               # its first path component
 
 
 def seed_words(seed: int) -> np.ndarray:
@@ -118,16 +123,56 @@ def _leaf_key(root, leaf: Leaf):
                               & 0x7FFFFFFF)
 
 
-def layer(config, words, l) -> Dict[str, jax.Array]:
-    """Layer ``l``'s leaves as served, keyed by path (no "layers/")."""
+def stacks(config) -> List[Tuple[str, int]]:
+    """The layer stacks in layer order as ``(stack, count)``: the
+    family's ``stacks(config)``, else one stack ``"layers"`` of every
+    layer.  A stack may come back in a later segment; its index then
+    continues.  Counts that do not sum to ``n_layers`` are refused."""
+    fam = family(config["family"])
+    segs = fam.stacks(config) if hasattr(fam, "stacks") \
+        else [("layers", config["n_layers"])]
+    segs = [(str(s), int(n)) for s, n in segs]
+    if any(n <= 0 for _, n in segs) \
+            or sum(n for _, n in segs) != config["n_layers"]:
+        raise ValueError(f"the family's stacks {segs} are not positive "
+                         f"counts summing to n_layers = "
+                         f"{config['n_layers']}")
+    return segs
+
+
+def layer_order(config) -> List[Tuple[str, int]]:
+    """``(stack, i)`` of global layers ``0..n_layers-1``: ``i`` is the
+    layer's index within its stack."""
+    seen: Dict[str, int] = {}
+    out = []
+    for s, n in stacks(config):
+        first = seen.get(s, 0)
+        out += [(s, first + i) for i in range(n)]
+        seen[s] = first + n
+    return out
+
+
+def stack_counts(config) -> Dict[str, int]:
+    """Layers in each stack."""
+    return dict(Counter(s for s, _ in layer_order(config)))
+
+
+def stack_of(leaf: Leaf) -> str:
+    """The stack a stacked leaf lies in: its path's first component."""
+    return leaf.path.split("/", 1)[0]
+
+
+def layer(config, words, i, stack="layers") -> Dict[str, jax.Array]:
+    """Layer ``i`` of ``stack``: its leaves as served, keyed by path
+    without the ``"<stack>/"`` prefix."""
     root = root_key(words)
-    return {lf.path[len("layers/"):]:
-            draw(lf, jax.random.fold_in(_leaf_key(root, lf), l))
-            for lf in leaves(config) if lf.stacked}
+    return {lf.path[len(stack) + 1:]:
+            draw(lf, jax.random.fold_in(_leaf_key(root, lf), i))
+            for lf in leaves(config) if lf.stacked and stack_of(lf) == stack}
 
 
 def globals_(config, words) -> Dict[str, jax.Array]:
-    """The leaves outside the layer stack, as served."""
+    """The leaves outside the layer stacks, as served."""
     root = root_key(words)
     return {lf.path: draw(lf, _leaf_key(root, lf))
             for lf in leaves(config) if not lf.stacked}
@@ -158,7 +203,8 @@ def place(config, abstract_params, shardings, seed: int):
 def placer(config, abstract_params, shardings):
     """The jitted draw of the program's parameter tree from the seed's
     words.  A leaf that the program has and the configuration does not,
-    or the other way round, is an error."""
+    or the other way round, is an error, and so is a stack whose length
+    in the program differs from the family's count of it."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(abstract_params)
     by_path = {lf.path: lf for lf in leaves(config)}
     prog = [(_path(p), a) for p, a in flat]
@@ -172,6 +218,13 @@ def placer(config, abstract_params, shardings):
         if jnp.dtype(a.dtype) != jnp.dtype(DTYPES[by_path[p].dtype]):
             raise ValueError(f"{p}: program serves {a.dtype}, the "
                              f"configuration states {by_path[p].dtype}")
+    per_stack = stack_counts(config)
+    for p, a in prog:
+        lf = by_path[p]
+        if lf.stacked and per_stack.get(stack_of(lf)) != a.shape[0]:
+            raise ValueError(f"stack {stack_of(lf)!r}: the program holds "
+                             f"{a.shape[0]} layers ({p}), the family "
+                             f"{per_stack.get(stack_of(lf), 0)}")
 
     def build(words):
         root = root_key(words)
