@@ -18,7 +18,7 @@ import math
 import sys
 import tempfile
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from functools import partial
 from typing import Any, Dict, List
 
@@ -38,10 +38,24 @@ def log(msg: str) -> None:
 
 
 def program_config(config: Dict[str, Any]):
-    """The program's ArchConfig for ``config["arch"]``, which must state
-    the same sizes as the configuration file."""
+    """The program's ArchConfig for ``config["arch"]``: its published
+    entry, with each key that the configuration file cuts
+    (``spec.check_cut``) set to the file's value.  Each cut key must be
+    a field of the entry and its published value the entry's, and every
+    size the file states, cut or not, the ArchConfig's."""
     from repro.models.registry import get_arch
-    cfg = get_arch(config["arch"])
+    pub = get_arch(config["arch"])
+    cut = config["reduced"]
+    lacks = [k for k in cut if not hasattr(pub, k)]
+    if lacks:
+        raise ValueError(f"the program's {config['arch']!r} has no field "
+                         f"for the cut keys {lacks}")
+    differ = {k: (getattr(pub, k), config["published"][k]) for k in cut
+              if getattr(pub, k) != config["published"][k]}
+    if differ:
+        raise ValueError(f"published values differ from the program's "
+                         f"(program, file): {differ}")
+    cfg = replace(pub, **{k: config[k] for k in cut}) if cut else pub
     check_sizes(cfg, config)
     return cfg
 

@@ -3,7 +3,9 @@
 A cell (``workloads`` entry) names a configuration and a traffic mix.
 Each is one JSON file, found by its name:
 
-    configs/<config>.json     sizes as run, source, reduced, assumed
+    configs/<config>.json     sizes as run, source, reduced, assumed;
+                              where cut to one chip's share, each cut
+                              key's published value and the deployment
     traffic/<traffic>.json    driver, batch and lengths, their source
     cells/<cell>.json         the correctness limit
     metrics/<metric>.py       ``read(ctx)`` for one per-layer metric
@@ -11,8 +13,8 @@ Each is one JSON file, found by its name:
     families/<family>.py      a model family's sizes, weight leaves,
                               reference layer, step work and, where
                               its layers are not all of one kind, its
-                              layer stacks, named by the
-                              configuration's ``family``
+                              layer stacks and the keys it may cut,
+                              named by the configuration's ``family``
 
 Adding a cell adds files and entries; nothing here is edited.
 """
@@ -20,6 +22,7 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import re
 import sys
 from dataclasses import dataclass
 from pathlib import Path
@@ -97,6 +100,81 @@ def family(name: str):
                        f"bench_family_{name}")
 
 
+#: names of widths, which no cut touches, whatever a family allows
+WIDTH = re.compile(r"^d_|_(dim|rank|ff|state|expand|conv)$|^hidden_size$"
+                   r"|intermediate|top_?k|per_tok")
+
+
+def published_weight_bytes(config: Dict[str, Any]) -> float:
+    """Bytes of every weight of the published model: the configuration
+    with each cut key set back to its ``published`` value, and every row
+    of the embedding."""
+    from .counts import weight_bytes
+    pub = dict(config, **config.get("published", {}))
+    return weight_bytes(pub, pub["vocab"])
+
+
+def check_cut(config: Dict[str, Any]) -> None:
+    """Refuses a configuration cut in any other way than to one chip's
+    share of its published model.
+
+    ``reduced`` names the cut keys, ``published`` gives each its
+    published value, and ``deployment`` says over how many chips each
+    layer is divided, and how.  A cut is refused where the published
+    model's weights fit the configuration's ``chips``: only a model too
+    large for them is cut.  Only ``n_layers``, ``vocab`` and the keys of
+    the family's ``cuts(config) -> {key: least}`` may be cut, none a
+    width, each to no less than its floor and no more than its published
+    value.  The floors: the depth keeps every layer outside the last of
+    the family's stacks and four of that stack, the vocabulary an
+    eighth, each raised to the family's least."""
+    fam = family(config["family"])
+    cut = config["reduced"]
+    published = config.get("published", {})
+    for k in cut:
+        if k not in config:
+            raise SpecError(f"reduced names {k!r}, which the configuration "
+                            f"does not state")
+        if k not in published:
+            raise SpecError(f"the cut key {k!r} has no published value")
+    for k in published:
+        if k not in cut:
+            raise SpecError(f"{k!r} is stated as cut but reduced does not "
+                            f"name it")
+    if not cut:
+        return
+    if not config.get("deployment"):
+        raise SpecError("a cut configuration states no deployment")
+    from .peaks import PEAKS
+    from .weights import stacks
+    least = dict(fam.cuts(config)) if hasattr(fam, "cuts") else {}
+    segs = stacks(config)
+    outside = sum(n for s, n in segs if s != segs[-1][0])
+    floors = {"n_layers": lambda pub: min(pub, outside + 4),
+              "vocab": lambda pub: -(-pub // 8)}
+    for k in cut:
+        if WIDTH.search(k):
+            raise SpecError(f"{k!r} is a width, which no cut touches")
+        if k not in floors and k not in least:
+            raise SpecError(f"{k!r} may not be cut: only "
+                            f"{sorted(set(floors) | set(least))}")
+        v, pub = config[k], published[k]
+        if not (isinstance(v, int) and isinstance(pub, int)):
+            raise SpecError(f"{k!r}: a cut and its published value are "
+                            f"counts, not {v!r} and {pub!r}")
+        floor = max(floors[k](pub) if k in floors else 0, least.get(k, 0))
+        if v < floor:
+            raise SpecError(f"{k!r} = {v} is under its floor {floor}")
+        if v > pub:
+            raise SpecError(f"{k!r} = {v} is above its published {pub}")
+    held = int(config["chips"]) * max(p.hbm_bytes for p in PEAKS.values())
+    need = published_weight_bytes(config)
+    if need <= held:
+        raise SpecError(f"the published model's weights, {need:.4g} bytes, "
+                        f"fit its {config['chips']} chip(s), {held:.4g} "
+                        f"bytes: nothing forces the cut {cut}")
+
+
 def _metrics(entries) -> List[Metric]:
     return [Metric(e["name"], e["unit"], e["better"], e["source"],
                    e.get("layer"), e.get("moves"), e.get("workloads"))
@@ -123,7 +201,7 @@ def cell(name: str, bench: Optional[Dict[str, Any]] = None) -> Cell:
     limits = _load_json(HERE / "cells" / f"{name}.json")
     e2e = [m for m in _metrics(bench["end_to_end"]) if m.applies_to(name)]
     layer = [m for m in _metrics(bench["per_layer"]) if m.applies_to(name)]
-    family(config["family"])
+    check_cut(config)
     if int(config["chips"]) != int(entry["chips"]):
         raise SpecError(f"{name}: config asks {config['chips']} chips, "
                         f"the cell {entry['chips']}")

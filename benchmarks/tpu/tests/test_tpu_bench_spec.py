@@ -18,7 +18,8 @@ def test_workload_resolves_to_its_files(name):
     assert cell.traffic["driver"]
     assert callable(cell.driver().run_window)
     assert isinstance(cell.limits["max_logit_gap"], float)
-    assert cell.config["reduced"] == []
+    assert set(cell.config["reduced"]) == set(cell.config.get("published",
+                                                             {}))
     assert cell.traffic["source"]
     assert all(isinstance(v, str) for v in cell.traffic["reduced"].values())
     fam = spec.family(cell.config["family"])
@@ -58,11 +59,10 @@ def test_entries_keep_to_the_contract():
 
 
 def test_config_files_state_the_program_sizes():
-    from repro.models.registry import get_arch
     from benchmarks.tpu import harness
     for w in BENCH["workloads"]:
         cell = spec.cell(w["name"], BENCH)
-        harness.check_sizes(get_arch(cell.config["arch"]), cell.config)
+        harness.program_config(cell.config)
 
 
 def test_unknown_workload_is_refused():
